@@ -216,7 +216,11 @@ def _noop() -> None:
 
 
 class InlineExecutor(Executor):
-    """Runs payloads synchronously in the event loop (deterministic tests)."""
+    """Runs payloads synchronously in the event loop (deterministic tests).
+
+    A payload's exception is recorded in ``errors[task.key]`` and the task
+    completes with ``ok=False``; interrupts and exits propagate.
+    """
 
     def __init__(self):
         self.results: Dict[Tuple[int, int], object] = {}
@@ -226,35 +230,24 @@ class InlineExecutor(Executor):
         ok = True
         try:
             if task.payload is not None:
-                self.results[task.key] = task.payload()
-        except BaseException as exc:        # noqa: BLE001
+                self.results[task.key] = self._finish(task.payload())
+        except Exception as exc:            # noqa: BLE001 — recorded
             ok = False
             self.errors[task.key] = exc
         done(ok)
+
+    @staticmethod
+    def _finish(out):
+        return out
 
 
 class JaxDispatchExecutor(InlineExecutor):
     """Payloads are JAX computations; blocks until device completion so the
-    measured per-task latency includes real dispatch + execution."""
+    measured per-task latency includes real dispatch + execution, and a
+    device-side failure is recorded against the task that caused it."""
 
-    def run(self, task: Task, done: Callable[[bool], None]) -> None:
-        ok = True
-        try:
-            if task.payload is not None:
-                out = task.payload()
-                out = _block(out)
-                self.results[task.key] = out
-        except BaseException as exc:        # noqa: BLE001
-            ok = False
-            self.errors[task.key] = exc
-        done(ok)
+    @staticmethod
+    def _finish(out):
+        import jax
 
-
-def _block(out):
-    import jax
-
-    leaves = jax.tree_util.tree_leaves(out)
-    for x in leaves:
-        if hasattr(x, "block_until_ready"):
-            x.block_until_ready()
-    return out
+        return jax.block_until_ready(out)

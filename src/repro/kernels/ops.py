@@ -1,9 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-Off-TPU the kernels run in interpret mode (the kernel body executes in
-Python on CPU) so the same call sites validate everywhere; on TPU they lower
-to Mosaic. Forward-only by design: training uses the XLA paths (chunked
-attention / chunked scan), serving and prefill use the kernels.
+On TPU the kernels lower to Mosaic. On the CPU backend they run in
+interpret mode (the kernel body executes as plain XLA ops), so the same call
+sites validate in CPU tests; any other backend is refused rather than
+silently interpreted. Forward-only by design: training uses the XLA paths
+(chunked attention / chunked scan), serving and prefill use the kernels.
 """
 from __future__ import annotations
 
@@ -18,7 +19,12 @@ from repro.kernels.ssm_scan import ssm_scan_fwd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and interpret on CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",

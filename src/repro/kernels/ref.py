@@ -72,3 +72,36 @@ def expert_gemm_ref(x, w):
     """Grouped expert matmul: x [E,M,K] @ w [E,K,N] -> [E,M,N] (fp32 accum)."""
     return jnp.einsum("emk,ekn->emn", x.astype(jnp.float32),
                       w.astype(jnp.float32)).astype(x.dtype)
+
+
+def slstm_scan_ref(pre, r_all, c0, n0, m0, h0):
+    """Sequential sLSTM recurrence, fp32.
+
+    pre: [B,S,4,d] preactivations; r_all: [4,H,dh,dh]; c0/n0/m0/h0:
+    [B,H,dh]. Returns (hs [B,S,d], (cT,nT,mT,hT) [B,H,dh]).
+    """
+    B, S, _, d = pre.shape
+    _, H, dh, _ = r_all.shape
+    r32 = r_all.astype(jnp.float32)
+
+    def cell(carry, pre_t):
+        c, n, m, h = carry
+        rec = jnp.einsum("bhk,ghkl->gbhl", h.reshape(B, H, dh),
+                         r32).reshape(4, B, d)
+        i = pre_t[:, 0] + rec[0]
+        f = pre_t[:, 1] + rec[1]
+        z = jnp.tanh(pre_t[:, 2] + rec[2])
+        o = jax.nn.sigmoid(pre_t[:, 3] + rec[3])
+        logf = jax.nn.log_sigmoid(f)
+        m_new = jnp.maximum(logf + m, i)
+        c = c * jnp.exp(logf + m - m_new) + jnp.exp(i - m_new) * z
+        n = n * jnp.exp(logf + m - m_new) + jnp.exp(i - m_new)
+        h = o * c / jnp.maximum(n, 1e-6)
+        return (c, n, m_new, h), h
+
+    carry = tuple(x.astype(jnp.float32).reshape(B, d)
+                  for x in (c0, n0, m0, h0))
+    carry, hs = jax.lax.scan(cell, carry,
+                             pre.astype(jnp.float32).swapaxes(0, 1))
+    return (hs.swapaxes(0, 1).astype(pre.dtype),
+            tuple(x.reshape(B, H, dh) for x in carry))

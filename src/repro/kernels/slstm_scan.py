@@ -13,8 +13,12 @@ to the TPU memory hierarchy.
 
 Grid: (heads, time-chunks), time innermost so VMEM scratch carries the state
 across chunks; per-head R blocks are grid-invariant along t (Mosaic skips
-the re-fetch). Within a chunk, a fori_loop steps the recurrence with one
-stacked [B,dh] x [4*dh? no: g,dh,dh] matvec batch per step on the MXU.
+the re-fetch). Layout is head-major, pre as [H, S, B, 4*dh]: a block is
+(1, chunk_t, B, 4*dh), whose last two dims are whole array dims, and a step
+reads one [B, 4*dh] row at a dynamic index on an untiled dim. The four
+gates' recurrent weights of a head are concatenated as [dh, 4*dh], so
+within a chunk a fori_loop steps the recurrence with one [B, dh] x
+[dh, 4*dh] matmul per step on the MXU.
 """
 from __future__ import annotations
 
@@ -29,26 +33,26 @@ DEFAULT_CHUNK_T = 256
 
 def _kernel(pre_ref, r_ref, c0_ref, n0_ref, m0_ref, h0_ref,
             hs_ref, cT_ref, nT_ref, mT_ref, hT_ref,
-            c_s, n_s, m_s, h_s, *, chunk: int, nt: int):
+            c_s, n_s, m_s, h_s, *, chunk: int, nt: int, dh: int):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _load():
-        c_s[...] = c0_ref[:, 0].astype(jnp.float32)
-        n_s[...] = n0_ref[:, 0].astype(jnp.float32)
-        m_s[...] = m0_ref[:, 0].astype(jnp.float32)
-        h_s[...] = h0_ref[:, 0].astype(jnp.float32)
+        c_s[...] = c0_ref[0].astype(jnp.float32)
+        n_s[...] = n0_ref[0].astype(jnp.float32)
+        m_s[...] = m0_ref[0].astype(jnp.float32)
+        h_s[...] = h0_ref[0].astype(jnp.float32)
 
-    r = r_ref[:, 0].astype(jnp.float32)      # [4, dh, dh]
+    r = r_ref[0].astype(jnp.float32)         # [dh, 4*dh]
 
     def step(t, _):
-        pre_t = pre_ref[:, t, :, 0].astype(jnp.float32)  # [B, 4, dh]
-        h = h_s[...]                                     # [B, dh]
-        rec = jnp.einsum("bk,gkl->gbl", h, r)            # [4, B, dh]
-        i_t = pre_t[:, 0] + rec[0]
-        f_t = pre_t[:, 1] + rec[1]
-        z_t = jnp.tanh(pre_t[:, 2] + rec[2])
-        o_t = jax.nn.sigmoid(pre_t[:, 3] + rec[3])
+        pre = pre_ref[0, t].astype(jnp.float32) + jax.lax.dot_general(
+            h_s[...], r, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [B, 4*dh]
+        i_t = pre[:, 0 * dh:1 * dh]
+        f_t = pre[:, 1 * dh:2 * dh]
+        z_t = jnp.tanh(pre[:, 2 * dh:3 * dh])
+        o_t = jax.nn.sigmoid(pre[:, 3 * dh:4 * dh])
         logf = jax.nn.log_sigmoid(f_t)
         m_new = jnp.maximum(logf + m_s[...], i_t)
         scale = jnp.exp(logf + m_s[...] - m_new)
@@ -60,17 +64,17 @@ def _kernel(pre_ref, r_ref, c0_ref, n0_ref, m0_ref, h0_ref,
         n_s[...] = n
         m_s[...] = m_new
         h_s[...] = h_new
-        hs_ref[:, t, 0] = h_new.astype(hs_ref.dtype)
+        hs_ref[0, t] = h_new.astype(hs_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
 
     @pl.when(ti == nt - 1)
     def _store():
-        cT_ref[:, 0] = c_s[...]
-        nT_ref[:, 0] = n_s[...]
-        mT_ref[:, 0] = m_s[...]
-        hT_ref[:, 0] = h_s[...]
+        cT_ref[0] = c_s[...]
+        nT_ref[0] = n_s[...]
+        mT_ref[0] = m_s[...]
+        hT_ref[0] = h_s[...]
 
 
 def slstm_scan_fwd(pre, r_all, c0, n0, m0, h0, *,
@@ -84,34 +88,33 @@ def slstm_scan_fwd(pre, r_all, c0, n0, m0, h0, *,
     chunk_t = min(chunk_t, S)
     assert S % chunk_t == 0
     nt = S // chunk_t
-    # head-major layout for per-head blocks: pre -> [B,S,4,H,dh]
-    pre_h = pre.reshape(B, S, 4, H, dh)
+    pre_h = (pre.reshape(B, S, 4, H, dh).transpose(3, 1, 0, 2, 4)
+             .reshape(H, S, B, 4 * dh))
+    r_cat = r_all.transpose(1, 2, 0, 3).reshape(H, dh, 4 * dh)
+    states = [x.swapaxes(0, 1) for x in (c0, n0, m0, h0)]   # [H,B,dh]
 
-    kernel = functools.partial(_kernel, chunk=chunk_t, nt=nt)
-    state_spec = pl.BlockSpec((B, 1, dh), lambda h, t: (0, h, 0))
-    hs, cT, nT, mT, hT = pl.pallas_call(
+    kernel = functools.partial(_kernel, chunk=chunk_t, nt=nt, dh=dh)
+    state_spec = pl.BlockSpec((1, B, dh), lambda h, t: (h, 0, 0))
+    state_shape = jax.ShapeDtypeStruct((H, B, dh), jnp.float32)
+    hs, *final = pl.pallas_call(
         kernel,
         grid=(H, nt),
         in_specs=[
-            pl.BlockSpec((B, chunk_t, 4, 1, dh), lambda h, t: (0, t, 0, h, 0)),
-            pl.BlockSpec((4, 1, dh, dh), lambda h, t: (0, h, 0, 0)),
+            pl.BlockSpec((1, chunk_t, B, 4 * dh), lambda h, t: (h, t, 0, 0)),
+            pl.BlockSpec((1, dh, 4 * dh), lambda h, t: (h, 0, 0)),
             state_spec, state_spec, state_spec, state_spec,
         ],
         out_specs=[
-            pl.BlockSpec((B, chunk_t, 1, dh), lambda h, t: (0, t, h, 0)),
+            pl.BlockSpec((1, chunk_t, B, dh), lambda h, t: (h, t, 0, 0)),
             state_spec, state_spec, state_spec, state_spec,
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, dh), pre.dtype),
-            jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((H, S, B, dh), pre.dtype)]
+        + [state_shape] * 4,
         scratch_shapes=[_vmem((B, dh), jnp.float32) for _ in range(4)],
         interpret=interpret,
-    )(pre_h, r_all, c0, n0, m0, h0)
-    return hs.reshape(B, S, d), (cT, nT, mT, hT)
+    )(pre_h, r_cat, *states)
+    hs = hs.transpose(2, 1, 0, 3).reshape(B, S, d)
+    return hs, tuple(x.swapaxes(0, 1) for x in final)
 
 
 def _vmem(shape, dtype):

@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", ""))
-# NOTE: the two lines above MUST run before any other import (including
-# repro.*) — JAX locks the device count on first initialization.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves the distribution config is coherent (shardings
@@ -172,6 +165,10 @@ def _write(out_dir: Path, rec: dict, extra_tag: str = "") -> None:
 
 
 def main() -> None:
+    # 512 host devices stand in for the production meshes. Set here, before
+    # JAX creates its CPU client, so that importing this module changes
+    # nothing.
+    jax.config.update("jax_num_cpu_devices", 512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

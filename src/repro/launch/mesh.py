@@ -10,29 +10,24 @@ never touches JAX device state.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    # jax < 0.5 has neither sharding.AxisType nor make_mesh(axis_types=...);
-    # Auto is that era's only behaviour, so omitting the kwarg is equivalent
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """Mesh over ``devices`` (default: all of them) with Auto axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh() -> Mesh:

@@ -1,7 +1,9 @@
-"""Serving driver: continuous-batching engine over a (smoke) model.
+"""Serving driver: continuous-batching engine over a model config.
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma_2b --smoke \
       --requests 32 --lanes 8
+
+Without ``--smoke`` the full published config is served.
 """
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ServeRequest, ServingEngine
 
@@ -18,7 +21,8 @@ from repro.serving import ServeRequest, ServingEngine
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma_2b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced same-family config")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=12)
@@ -27,9 +31,11 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # one compiled init: eager init holds every layer and its stacked copy
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     engine = ServingEngine(cfg, params, lanes=args.lanes,
                            max_len=args.max_len)
     rng = np.random.default_rng(args.seed)

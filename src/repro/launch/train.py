@@ -22,6 +22,7 @@ from repro.configs import RunConfig, get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.data import SyntheticTokens, TokenPipeline
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import build_train_step, rules_for
 from repro.models import build_model
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = (make_host_mesh() if args.mesh == "host"
             else make_production_mesh(multi_pod=(args.mesh == "multi")))

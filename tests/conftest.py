@@ -1,8 +1,5 @@
 import os
-import sys
 
-# Tests run against a single CPU device (the dry-run sets its own 512-device
-# flag in its own process). Keep compile times sane.
+# Tests run against a single CPU device, with Pallas kernels in interpret
+# mode (the dry-run asks for its 512 host devices in its own process).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -64,6 +64,7 @@ def test_flash_attention_softcap():
     (1, 32, 64, 8, 64),
     (2, 64, 128, 16, 64),
     (1, 48, 256, 4, 128),
+    (1, 200, 64, 8, 64),      # two lane slabs, zero-padded tail
 ])
 def test_ssm_scan_matches_ref(dtype, Bb, S, d, N, bd):
     u = _rand((Bb, S, d), dtype)
@@ -188,29 +189,12 @@ def test_slstm_scan_matches_sequential(B, S, H, dh, chunk):
     hs, (cT, nT, mT, hT) = ops.slstm_scan(pre, r, zeros, zeros, minf, zeros,
                                           chunk_t=chunk)
 
-    def cell(carry, pre_t):
-        c, n, m, h = carry
-        hh = h.reshape(B, H, dh)
-        rec = jnp.einsum("bhk,ghkl->gbhl", hh, r).reshape(4, B, d)
-        i = pre_t[:, 0] + rec[0]
-        f = pre_t[:, 1] + rec[1]
-        z = jnp.tanh(pre_t[:, 2] + rec[2])
-        o = jax.nn.sigmoid(pre_t[:, 3] + rec[3])
-        logf = jax.nn.log_sigmoid(f)
-        m_new = jnp.maximum(logf + m, i)
-        c = c * jnp.exp(logf + m - m_new) + jnp.exp(i - m_new) * z
-        n = n * jnp.exp(logf + m - m_new) + jnp.exp(i - m_new)
-        h = o * c / jnp.maximum(n, 1e-6)
-        return (c, n, m_new, h), h
-
-    carry = (jnp.zeros((B, d)), jnp.zeros((B, d)), jnp.full((B, d), -1e30),
-             jnp.zeros((B, d)))
-    carry, hs_ref = jax.lax.scan(cell, carry, pre.swapaxes(0, 1))
-    np.testing.assert_allclose(np.asarray(hs),
-                               np.asarray(hs_ref.swapaxes(0, 1)),
+    hs_ref, final = ref.slstm_scan_ref(pre, r, zeros, zeros, minf, zeros)
+    np.testing.assert_allclose(np.asarray(hs), np.asarray(hs_ref),
                                atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(hT.reshape(B, d)),
-                               np.asarray(carry[3]), atol=1e-5, rtol=1e-5)
+    for got, exp in zip((cT, nT, mT, hT), final):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                   atol=1e-5, rtol=1e-5)
 
 
 def test_slstm_model_kernel_path_matches_xla_path():
