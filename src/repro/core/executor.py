@@ -28,6 +28,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.core.job import Task
 from repro.core.scheduler import Executor
+from repro.obs.spans import span
 
 #: queue sentinel that wakes a blocked worker ``get()`` at shutdown
 _STOP = object()
@@ -218,6 +219,11 @@ def _noop() -> None:
 class InlineExecutor(Executor):
     """Runs payloads synchronously in the event loop (deterministic tests).
 
+    While a profile is being captured, the payload call is span
+    ``exec.dispatch`` (for a JAX payload: tracing and enqueueing its
+    programs) and the wait for its result ``exec.wait``, both keyed by the
+    task's ``(job, index)``.
+
     A payload's exception is recorded in ``errors[task.key]`` and the task
     completes with ``ok=False``; interrupts and exits propagate.
     """
@@ -228,12 +234,16 @@ class InlineExecutor(Executor):
 
     def run(self, task: Task, done: Callable[[bool], None]) -> None:
         ok = True
+        key = task.key
         try:
             if task.payload is not None:
-                self.results[task.key] = self._finish(task.payload())
+                with span("exec.dispatch", key):
+                    out = task.payload()
+                with span("exec.wait", key):
+                    self.results[key] = self._finish(out)
         except Exception as exc:            # noqa: BLE001 — recorded
             ok = False
-            self.errors[task.key] = exc
+            self.errors[key] = exc
         done(ok)
 
     @staticmethod

@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence
 
 from repro.core.job import Job, ResourceRequest, Task
+from repro.obs.spans import span
 
 
 @dataclass(frozen=True)
@@ -47,8 +48,13 @@ def aggregate(job: Job, slots: int,
     The bundled job is what actually hits the scheduler; per-bundle duration
     models the map application processing its slice of inputs sequentially.
     Payloads (real mode) are composed into one callable per bundle.
+    Span ``multilevel.aggregate``, keyed by the job's id.
     """
-    cfg = cfg or MultilevelConfig()
+    with span("multilevel.aggregate", job.job_id):
+        return _aggregate(job, slots, cfg or MultilevelConfig())
+
+
+def _aggregate(job: Job, slots: int, cfg: MultilevelConfig) -> Job:
     n_bundles = min(slots * cfg.bundles_per_slot, job.n_tasks) or 1
     per_bundle = math.ceil(job.n_tasks / n_bundles)
     durations: List[float] = []

@@ -5,7 +5,7 @@ companion study (Reuther et al., "Scheduler Technologies in Support of High
 Performance Data Analysis") shows that what separates schedulers at short
 job durations is where that time goes — admission, policy cycle, dispatch,
 completion handling, failure detection.  This module attributes our own
-engine's real (``perf_counter``) time to those phases.
+engine's real (``perf_counter_ns``) time to those phases.
 
 Mechanics: the profiler wraps a fixed set of scheduler entry points as
 *instance* attributes (internal calls and event-loop callbacks resolve
@@ -20,11 +20,18 @@ Overhead control (Byun et al.: instrumentation must be O(1)-amortized or it
 perturbs short-job regimes): ``stride=N`` times only every Nth call per
 phase, scaling the sampled self time by N — an unbiased estimate when call
 costs are i.i.d. within a phase.  ``stride=1`` (default) is exact.
+
+Times are on the span clock (``obs/spans.py``), and while a profile is
+being captured each sampled call is also span ``sched.<phase>``.  A span's
+own enter and exit lie outside the interval timed for its phase, and a
+nested call charges its enclosing frame from before its span opened to
+after it closed, so the spans' cost is in no phase's self time.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List
+
+from repro.obs.spans import clock, span
 
 __all__ = ["SelfProfiler"]
 
@@ -76,7 +83,8 @@ class SelfProfiler:
             fn = getattr(sch, attr, None)
             if fn is None:
                 continue
-            setattr(sch, attr, self._wrap(fn, self.stats[phase]))
+            setattr(sch, attr, self._wrap(fn, self.stats[phase],
+                                          "sched." + phase))
             self._wrapped.append(attr)
         return self
 
@@ -94,29 +102,35 @@ class SelfProfiler:
         self._sch = None
         return self
 
-    def _wrap(self, fn, st: PhaseStat):
+    def _wrap(self, fn, st: PhaseStat, name: str):
         stride = self.stride
         stack = self._stack
-        pc = time.perf_counter
+        scale = stride * 1e-9
 
         def timed(*args, **kw):
             st.calls += 1
             if st.calls % stride:        # unsampled call: zero added cost
                 return fn(*args, **kw)
-            frame = [0.0]
+            frame = [0]                  # ns of nested sampled calls
             stack.append(frame)
-            t0 = pc()
+            dt = 0
+            t_out = clock()
             try:
-                return fn(*args, **kw)
+                with span(name):
+                    t0 = clock()
+                    try:
+                        return fn(*args, **kw)
+                    finally:
+                        dt = clock() - t0
             finally:
-                dt = pc() - t0
                 stack.pop()
                 st.sampled += 1
-                st.self_s += (dt - frame[0]) * stride
+                st.self_s += (dt - frame[0]) * scale
                 if stack:
-                    # inclusive time charges the enclosing sampled frame,
-                    # whatever its phase — self times never double count
-                    stack[-1][0] += dt
+                    # inclusive time, the span's enter and exit with it,
+                    # charges the enclosing sampled frame, whatever its
+                    # phase — self times never double count
+                    stack[-1][0] += clock() - t_out
         return timed
 
     # ----------------------------------------------------------- reading

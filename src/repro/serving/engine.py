@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import collections
 import itertools
-import time
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Sequence
 
@@ -31,6 +30,7 @@ from repro.core.job import Job, ResourceRequest, Task
 from repro.core.resources import ResourceManager
 from repro.models import build_model
 from repro.models.transformer import init_caches
+from repro.obs.spans import clock, mark, span
 
 _req_ids = itertools.count(1)
 
@@ -41,11 +41,11 @@ class ServeRequest:
     max_new_tokens: int = 16
     eos_token: int = -1
     request_id: int = field(default_factory=lambda: next(_req_ids))
-    # filled by the engine
+    # filled by the engine, times in ns on the span clock (obs/spans.py)
     output: List[int] = field(default_factory=list)
-    submit_time: float = 0.0
-    first_token_time: float = 0.0
-    done_time: float = 0.0
+    submit_time: int = 0
+    first_token_time: int = 0
+    done_time: int = 0
 
     @property
     def done(self) -> bool:
@@ -95,37 +95,51 @@ class ServingEngine:
 
     # ------------------------------------------------------------ admit
     def submit(self, req: ServeRequest) -> None:
-        req.submit_time = time.time()
+        req.submit_time = clock()
         self.pending.append(req)
 
     def _admit(self) -> None:
+        """Admit pending requests into free lanes. Spans, keyed by the
+        request's id: ``engine.queue`` (submission to the start of its
+        admission), ``engine.admit``, and inside it ``engine.prefill``,
+        ``engine.scatter`` and ``engine.first_token``."""
         while self.pending:
             free = [i for i in range(self.lanes) if not self.active_mask[i]]
             if not free:
                 return
             lane = free[0]
             req = self.pending.popleft()
-            task_job = Job.array(1, name=f"req{req.request_id}")
-            self.rm.allocate(task_job.tasks[0], lane)
-            self._lane_jobs[lane] = task_job.tasks[0]
-            # prefill into this lane
+            rid = req.request_id
+            mark("engine.queue", req.submit_time, rid)
+            with span("engine.admit", rid):
+                self._admit_one(req, lane)
+
+    def _admit_one(self, req: ServeRequest, lane: int) -> None:
+        rid = req.request_id
+        task_job = Job.array(1, name=f"req{rid}")
+        self.rm.allocate(task_job.tasks[0], lane)
+        self._lane_jobs[lane] = task_job.tasks[0]
+        # prefill into this lane
+        with span("engine.prefill", rid):
             prompt = jnp.asarray(req.prompt, jnp.int32)[None]
             next_tok, new_caches = self._prefill_one(self.params, prompt)
+        with span("engine.scatter", rid):
             self._scatter_lane(lane, new_caches)
+        with span("engine.first_token", rid):
             tok = int(next_tok[0])
-            req.output.append(tok)
-            req.first_token_time = time.time()
-            if req.done:
-                # generation stops at the step that produces EOS — when the
-                # prefill token is already terminal (EOS, or
-                # max_new_tokens == 1), activating the lane would burn a
-                # decode dispatch and emit one extra post-EOS token
-                req.done_time = time.time()
-                self.rm.release(self._lane_jobs.pop(lane))
-                continue
-            self.positions[lane] = len(req.prompt)
-            self.lane_req[lane] = req
-            self.active_mask[lane] = True
+        req.output.append(tok)
+        req.first_token_time = clock()
+        if req.done:
+            # generation stops at the step that produces EOS — when the
+            # prefill token is already terminal (EOS, or max_new_tokens ==
+            # 1), activating the lane would burn a decode dispatch and emit
+            # one extra post-EOS token
+            req.done_time = clock()
+            self.rm.release(self._lane_jobs.pop(lane))
+            return
+        self.positions[lane] = len(req.prompt)
+        self.lane_req[lane] = req
+        self.active_mask[lane] = True
 
     def _scatter_lane(self, lane: int, src_caches) -> None:
         """Copy a 1-lane cache pytree into lane `lane` of the engine cache."""
@@ -137,44 +151,54 @@ class ServingEngine:
 
     # ------------------------------------------------------------- step
     def step(self) -> int:
-        """Admit + one batched decode step; returns #active lanes."""
+        """Admit + one batched decode step; returns #active lanes.
+
+        Spans of the decode step, keyed by its number (``steps`` before
+        it): ``engine.prepare`` (the token batch), ``engine.decode`` (the
+        jitted call), ``engine.sync`` (waiting for its tokens) and
+        ``engine.retire`` (appending them, freeing finished lanes)."""
         self._admit()
         active = np.nonzero(self.active_mask)[0]
         if len(active) == 0:
             return 0
-        tokens = np.zeros((self.lanes, 1), np.int32)
-        for i in range(self.lanes):
-            r = self.lane_req[i]
-            if r is not None:
-                tokens[i, 0] = r.output[-1]
-        next_tok, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(self.positions))
-        next_np = np.asarray(next_tok)
-        self.steps += 1
-        self.decode_tokens += len(active)
-        for lane in active:
-            req = self.lane_req[lane]
-            req.output.append(int(next_np[lane]))
-            self.positions[lane] += 1
-            if req.done or self.positions[lane] >= self.max_len - 1:
-                req.done_time = time.time()
-                self.active_mask[lane] = False
-                self.lane_req[lane] = None
-                task = self._lane_jobs.pop(lane, None)
-                if task is not None:
-                    self.rm.release(task)
+        n = self.steps
+        with span("engine.prepare", n):
+            tokens = np.zeros((self.lanes, 1), np.int32)
+            for i in range(self.lanes):
+                r = self.lane_req[i]
+                if r is not None:
+                    tokens[i, 0] = r.output[-1]
+        with span("engine.decode", n):
+            next_tok, self.caches = self._decode(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(self.positions))
+        with span("engine.sync", n):
+            next_np = np.asarray(next_tok)
+        with span("engine.retire", n):
+            self.steps += 1
+            self.decode_tokens += len(active)
+            for lane in active:
+                req = self.lane_req[lane]
+                req.output.append(int(next_np[lane]))
+                self.positions[lane] += 1
+                if req.done or self.positions[lane] >= self.max_len - 1:
+                    req.done_time = clock()
+                    self.active_mask[lane] = False
+                    self.lane_req[lane] = None
+                    task = self._lane_jobs.pop(lane, None)
+                    if task is not None:
+                        self.rm.release(task)
         return len(active)
 
     def run(self, requests: Sequence[ServeRequest]) -> Dict:
         """Serve a batch of requests to completion; returns summary stats."""
-        t0 = time.time()
+        t0 = clock()
         for r in requests:
             self.submit(r)
         while self.pending or self.active_mask.any():
             self.step()
-        wall = time.time() - t0
-        lat = [r.done_time - r.submit_time for r in requests]
+        wall = (clock() - t0) * 1e-9
+        lat = [(r.done_time - r.submit_time) * 1e-9 for r in requests]
         return {
             "wall_s": wall,
             "requests": len(requests),
