@@ -3,8 +3,10 @@
   SimExecutor     virtual time (the engine schedules end events directly).
   ThreadExecutor  real wall-clock execution of Python payloads on a worker
                   pool — used to measure *real* dispatch overheads.
-  JaxDispatchExecutor  payloads are jitted JAX computations; measures real
-                  JAX dispatch latency t_s, and demonstrates multilevel
+  JaxDispatchExecutor  payloads are jitted JAX computations, enqueued on
+                  the device through a bounded window of tasks in flight;
+                  a task completes once its own output is ready.  Measures
+                  real JAX dispatch latency t_s, and demonstrates multilevel
                   scheduling as dispatch aggregation (DESIGN.md §2).
 
 Real-time use drives the same EventLoop with wall-deadline semantics: the
@@ -21,14 +23,15 @@ primitive for transport messages.
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from repro.core.job import Task
 from repro.core.scheduler import Executor
-from repro.obs.spans import span
+from repro.obs.spans import clock, mark, span
 
 #: queue sentinel that wakes a blocked worker ``get()`` at shutdown
 _STOP = object()
@@ -252,9 +255,103 @@ class InlineExecutor(Executor):
 
 
 class JaxDispatchExecutor(InlineExecutor):
-    """Payloads are JAX computations; blocks until device completion so the
-    measured per-task latency includes real dispatch + execution, and a
-    device-side failure is recorded against the task that caused it."""
+    """Payloads are JAX computations, run through a bounded window of tasks
+    in flight on the device.
+
+    ``run`` calls the payload (span ``exec.dispatch``), which with JAX's
+    asynchronous dispatch only enqueues its programs, and returns with the
+    task in flight. Once ``window`` tasks are in flight, counting the one
+    just dispatched, the oldest is retired: its output is waited for
+    (``_finish``, span ``exec.wait``) and kept in ``results[task.key]``, or
+    a device-side error is recorded in ``errors[task.key]`` against the
+    task that raised it; only then is its ``done`` scheduled, as an event
+    at the loop's current instant. A task is therefore never reported
+    complete before its own output is ready.
+
+    Completions are events on the loop that :meth:`bind_loop` binds (the
+    Scheduler does so), and ``run`` refuses to start without one. When the
+    loop's heap runs dry with tasks in flight, the drain source registered
+    there retires the oldest; :meth:`settle` retires them all, for the
+    Scheduler to call before anything that judges running tasks by their
+    age (heartbeat sweeps, speculation) and when a horizon stops its loop.
+    So no run of the Scheduler ends with work on the device.
+
+    While a profile is captured, each retirement also records the mark
+    ``exec.inflight``, from the start of the task's dispatch to the moment
+    its result is collected.
+    """
+
+    #: most tasks in flight at once, the one being dispatched included:
+    #: the smallest depth at which the direct task-set cell stops gaining
+    #: on a v5e (PERF.md §6; 51 s runs, two seeds a depth: 1,157–1,175
+    #: tasks/s at 2, 1,196–1,205 at 3 and at 4)
+    window = 3
+
+    def __init__(self):
+        super().__init__()
+        self._loop = None
+        #: (task, done, output, dispatch start on the span clock), oldest first
+        self._inflight: Deque[tuple] = collections.deque()
+
+    @property
+    def inflight(self) -> int:
+        """Tasks dispatched whose outputs have not been collected yet."""
+        return len(self._inflight)
+
+    def run(self, task: Task, done: Callable[[bool], None]) -> None:
+        if self._loop is None:
+            raise RuntimeError("JaxDispatchExecutor completes tasks as "
+                               "events: bind a loop first (a Scheduler "
+                               "does)")
+        key = task.key
+        t0 = clock()
+        try:
+            with span("exec.dispatch", key):
+                out = task.payload()
+        except Exception as exc:            # noqa: BLE001 — recorded
+            self.errors[key] = exc
+            self._loop.at(self._loop.now, done, False)
+            return
+        self._inflight.append((task, done, out, t0))
+        if len(self._inflight) >= self.window:
+            self._retire()
+
+    def bind_loop(self, loop) -> None:
+        """Complete tasks as events on ``loop``, and retire the oldest task
+        in flight whenever its heap runs dry."""
+        if self._loop is loop:
+            return
+        self._loop = loop
+        loop.add_source(self._drain_source)
+
+    def settle(self) -> bool:
+        """Retire every task in flight, oldest first, so that their
+        completions are the next events at the loop's current instant.
+        True if there were any."""
+        if not self._inflight:
+            return False
+        while self._inflight:
+            self._retire()
+        return True
+
+    def _drain_source(self) -> bool:
+        if not self._inflight:
+            return False
+        self._retire()
+        return True
+
+    def _retire(self) -> None:
+        task, done, out, t0 = self._inflight.popleft()
+        key = task.key
+        ok = True
+        try:
+            with span("exec.wait", key):
+                self.results[key] = self._finish(out)
+        except Exception as exc:            # noqa: BLE001 — recorded
+            ok = False
+            self.errors[key] = exc
+        mark("exec.inflight", t0, key=key)
+        self._loop.at(self._loop.now, done, ok)
 
     @staticmethod
     def _finish(out):

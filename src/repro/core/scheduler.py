@@ -287,6 +287,10 @@ class Scheduler:
         # events, serialized with every other engine state change
         if executor is not None and hasattr(executor, "bind_loop"):
             executor.bind_loop(self.loop)
+        # an executor that holds dispatched tasks in flight past their
+        # dispatch event (JaxDispatchExecutor's window) retires them all on
+        # ``settle()``; see _settle
+        self._settle_executor = getattr(executor, "settle", None)
 
     # ----------------------------------------------------------- submit
     def submit(self, job: Job) -> None:
@@ -2130,6 +2134,8 @@ class Scheduler:
         via the down callback).  Re-arms itself while jobs are in flight;
         goes quiet when idle and is re-armed by the next ``submit``, so an
         idle engine's event loop can still drain."""
+        if self._settle(self._heartbeat_sweep):
+            return                     # still armed: sweeps after them
         self._sweep_armed = False
         newly_down = self.rm.sweep_heartbeats(self.loop.now)
         if self.on_sweep is not None:
@@ -2309,6 +2315,17 @@ class Scheduler:
         if self._active_jobs:
             self._request_cycle()
 
+    def _settle(self, then: Callable[[], None]) -> bool:
+        """Before ``then`` judges running tasks by their age or their node's
+        heartbeat: retire the tasks the executor still holds in flight,
+        whose completions the loop has not seen. True if there were any;
+        their completions are then queued at this instant with ``then``
+        after them, and the caller returns."""
+        if self._settle_executor is None or not self._settle_executor():
+            return False
+        self.loop.at(self.loop.now, then)
+        return True
+
     def fail_node(self, node_id: int) -> None:
         self.rm.mark_down(node_id)
 
@@ -2318,6 +2335,8 @@ class Scheduler:
         Walks the running-task index (bounded by occupied slots) instead of
         every task of every active job.
         """
+        if self._settle(self._speculate):
+            return
         if len(self._durations) < 8 or not self._free_stack:
             return
         # amortized median: recompute only when a completion changed the
@@ -2383,6 +2402,10 @@ class Scheduler:
     # ------------------------------------------------------------- run
     def run(self, until: float = float("inf")) -> None:
         self.loop.run(until)
+        # a horizon can stop the loop with tasks in the executor's window:
+        # collect them, and run what their completions start before it
+        while self._settle_executor is not None and self._settle_executor():
+            self.loop.run(until)
 
     @property
     def active_jobs(self) -> int:
