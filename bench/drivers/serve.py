@@ -1,5 +1,7 @@
-"""Serving cells: a dense model answers an open-loop stream of requests
-through ``ServingEngine.submit`` / ``ServingEngine.step``.
+"""Serving cells: a model answers an open-loop stream of requests through
+``ServingEngine.submit`` / ``ServingEngine.step``. The configuration file's
+``"model"`` names the module (``bench/models/<model>.py``) that gives the
+program's config and weights, the counts and the reference's check.
 
 Set-up makes the weights on the device from the seed in one jitted call,
 builds the engine, and warms every prompt length the window offers, every
@@ -15,77 +17,17 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from bench import traffic
-from bench.counts import Dense
-from bench.reference import dense
-
-#: program fields set from a configuration file's published keys
-_PUBLISHED = {
-    "n_layers": "num_hidden_layers", "d_model": "hidden_size",
-    "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
-    "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rope_fraction": "partial_rotary_factor",
-    "norm_eps": "rms_norm_eps",
-}
 
 
-def model_config(doc: dict):
-    """The program's config for this file: its named repo config with every
-    published size set from the file (the file, not the repo, is the
-    yardstick)."""
-    from repro.configs import get_config
-
-    base = get_config(doc["program_config"])
-    cfg = dataclasses.replace(
-        base, **{f: doc[k] for f, k in _PUBLISHED.items()},
-        head_dim=doc["hidden_size"] // doc["num_attention_heads"],
-        act="swiglu", tie_embeddings=doc["tie_word_embeddings"],
-        sliding_window=0, attn_logit_softcap=0.0, dtype="bfloat16",
-        max_seq_len=max(base.max_seq_len, doc["engine"]["max_len"]))
-    if cfg.family != "dense" or cfg.resolved_scan_period != 1:
-        raise ValueError(f"{cfg.name} is not a plain dense stack")
-    changed = {f: (getattr(base, f), getattr(cfg, f))
-               for f in _PUBLISHED if getattr(base, f) != getattr(cfg, f)}
-    return cfg, changed
-
-
-def program_params(cfg, sh: dense.Shapes, seed: int):
-    """The program's parameter tree, made on the device in one call."""
-    from repro.models import build_model
-
-    def make(key):
-        layers = jax.vmap(lambda l: dense.layer_weights(sh, key, l))(
-            jnp.arange(sh.layers))
-        emb, final = dense.embed_weights(sh, key)
-        pad = cfg.padded_vocab - sh.vocab
-        return {
-            "embed": {"tok_embed": jnp.pad(emb, ((0, pad), (0, 0)))},
-            "stack": {"pos00": {
-                "mixer_norm": {"scale": layers["attn_norm"]},
-                "mixer": {"wq": dense.interleave_rope(layers["wq"],
-                                                      sh.rope_dims),
-                          "wk": dense.interleave_rope(layers["wk"],
-                                                      sh.rope_dims),
-                          "wv": layers["wv"], "wo": layers["wo"]},
-                "ffn_norm": {"scale": layers["ffn_norm"]},
-                "ffn": {"w_gate": layers["w_gate"], "w_up": layers["w_up"],
-                        "w_down": layers["w_down"]}},
-                "final_norm": {"scale": final}}}
-
-    key = dense.root_key(seed)
-    want = build_model(cfg).param_specs()
-    got = jax.eval_shape(make, key)
-    if jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), got) != \
-            jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), want):
-        raise ValueError("the program's parameter layout changed: "
-                         f"{jax.tree_util.tree_structure(want)}")
-    return jax.block_until_ready(jax.jit(make)(key))
+def model(doc: dict):
+    """``bench/models/<doc["model"]>.py``."""
+    return importlib.import_module(f"bench.models.{doc['model']}")
 
 
 def _prompt(rng, n, vocab):
@@ -98,10 +40,11 @@ def build(doc: dict, prompt_lens, seed: int, mark=None):
     step."""
     from repro.serving import ServeRequest, ServingEngine
 
-    cfg, changed = model_config(doc)
-    sh = dense.Shapes.of(doc)
+    m = model(doc)
+    cfg, changed = m.program_config(doc)
+    sh = m.shapes(doc)
     eng = doc["engine"]
-    params = program_params(cfg, sh, seed)
+    params = m.program_params(cfg, sh, seed)
     if mark:
         mark("weights")
     engine = ServingEngine(cfg, params, lanes=eng["lanes"],
@@ -197,6 +140,7 @@ def tails(served: Served, reqs, plan) -> dict:
 
 def run(ctx):
     doc, mix, seed = ctx.config, ctx.traffic, ctx.seed
+    m = model(doc)
     plan = traffic.requests(mix, ctx.seconds, seed)
     cfg, changed, sh, engine = build(
         doc, traffic.prompt_lengths(mix, ctx.seconds), seed, ctx.mark)
@@ -221,7 +165,7 @@ def run(ctx):
             **{k: t[k] for k in ("ttft_p90_ms", "tpot_p90_ms")})
     ctx.attempted, ctx.failed = len(reqs), len(reqs) - len(ok)
     ctx.end_to_end(ttft_p50_ms=t["ttft_p50_ms"], tpot_p50_ms=t["tpot_p50_ms"])
-    ctx.observe(shapes=Dense.of(cfg), ttft_p90_ms=t["ttft_p90_ms"])
+    ctx.observe(shapes=m.counts(cfg), ttft_p90_ms=t["ttft_p90_ms"])
 
     # ------------------------------------------------------- correctness
     t_check = time.perf_counter()
@@ -229,7 +173,7 @@ def run(ctx):
     seqs = [(reqs[i].prompt, reqs[i].output) for i in sample]
     del engine
     gc.collect()
-    gaps = dense.token_gaps(sh, seed, seqs, control=ctx.control) \
+    gaps = m.token_gaps(sh, seed, seqs, control=ctx.control) \
         if seqs else []
     worst = {k: max((float(g[k].max()) for g in gaps), default=0.0)
              for k in (("gap", "control_gap") if ctx.control else ("gap",))}

@@ -6,11 +6,29 @@ Everything a cell is made of is found by name, so a cell, configuration,
 traffic mix or per-layer metric is added by adding files and entries:
 
   BENCHMARK.json           the cell: its config, traffic and chips
-  bench/configs/<config>.json   sizes as run, and "driver"
-  bench/traffic/<cell>.json     the mix
+  bench/configs/<config>.json   sizes as run, "driver", and "smoke": the
+                                keys it overrides at the tests' CPU size
+  bench/traffic/<cell>.json     the mix, and its "smoke" keys
   bench/drivers/<driver>.py     runs a cell of that kind: run(ctx)
   bench/metrics/<metric>.py     read(obs) -> number, or None when the run
                                 left it nothing to read
+
+A served model of a new architecture needs no edit of these files either:
+
+  bench/configs/<config>.json   "driver": "serve", "model": "<model>", "smoke"
+  bench/models/<model>.py       program_config(doc) -> (cfg, changed),
+                                shapes(doc) (with .vocab),
+                                program_params(cfg, sh, seed),
+                                counts(cfg) (prefill_flops, decode_flops,
+                                decode_bytes), token_gaps(sh, seed, seqs,
+                                control=...)
+  bench/reference/<model>.py    its plain reference, which imports nothing
+                                of the program
+  bench/traffic/<cell>.json     with "smoke"
+  BENCHMARK.json           the config and the cell; the cell's name added
+                           to the "workloads" of each end-to-end and
+                           per-layer metric it reports, and new metric
+                           files for what the architecture adds
 
 The run exits non-zero, printing no result, when JAX finds no TPU or fewer
 chips than the cell asks for. Set-up (weights and data from the seed,

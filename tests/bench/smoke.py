@@ -1,27 +1,24 @@
-"""Smoke sizes of each driver's configuration and traffic, for CPU runs."""
+"""Smoke sizes for CPU runs: each configuration and traffic file holds the
+keys it overrides at smoke size in a ``"smoke"`` object (its ``"why"``
+says why, and is not merged)."""
 
 
-def taskset(cfg, mix):
-    return (dict(cfg, processors=8, task_matrix=128),
-            dict(mix, rounds=2, check_sample=8))
+def at_smoke(doc: dict, path: str) -> dict:
+    """``doc``, the file at ``path``, with its ``smoke`` keys set; a dict
+    is merged one level deep, as a mix's ``arrivals`` or a configuration's
+    ``engine``."""
+    if not isinstance(doc.get("smoke"), dict):
+        raise KeyError(f"{path} has no \"smoke\" object")
+    out = dict(doc)
+    for k, v in doc["smoke"].items():
+        if k == "why":
+            continue
+        out[k] = {**doc.get(k, {}), **v} if isinstance(v, dict) else v
+    return out
 
 
-def serve(cfg, mix):
-    cfg = dict(cfg, hidden_size=64, intermediate_size=128,
-               num_attention_heads=4, num_key_value_heads=2,
-               num_hidden_layers=2, vocab_size=509,
-               engine={"lanes": 4, "max_len": 96},
-               # logits at this width are about 7x smaller than at the
-               # published one; sound runs read ~0.003, the float8
-               # control ~0.08 (CPU)
-               limits={"max_logit_gap": 0.03})
-    mix = dict(mix, arrivals=dict(mix["arrivals"], rate=20.0),
-               prompt=dict(mix["prompt"], median=24, min=8, max=64,
-                           round_to=16),
-               output=dict(mix["output"], median=8, min=4, max=16),
-               check_sample=4)
-    return cfg, mix
-
-
-def shrink(cfg, mix):
-    return globals()[cfg["driver"]](cfg, mix)
+def shrink(cell: dict, cfg: dict, mix: dict):
+    """A cell's configuration and traffic (as ``bench.run.resolve`` gives
+    them) at smoke size."""
+    return (at_smoke(cfg, f"bench/configs/{cell['config']}.json"),
+            at_smoke(mix, f"bench/traffic/{cell['name']}.json"))

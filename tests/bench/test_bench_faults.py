@@ -26,7 +26,7 @@ def _run(cell, *, control=False, matrix=None):
     c = {"name": cell, "config": CELLS[cell], "traffic": cell, "chips": 1}
     doc = json.loads((ROOT / f"bench/configs/{c['config']}.json").read_text())
     mix = json.loads((ROOT / f"bench/traffic/{cell}.json").read_text())
-    doc, mix = shrink(doc, mix)
+    doc, mix = shrink(c, doc, mix)
     if matrix:
         doc = dict(doc, task_matrix=matrix)
     return run.run_cell(SPEC, c, doc, mix, seed=2 ** 31 + 5, seconds=0.5,

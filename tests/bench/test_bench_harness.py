@@ -129,7 +129,7 @@ def test_a_reader_with_nothing_to_read_returns_none(name):
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_runs_end_to_end_at_a_smoke_size(cell, trace):
     c, doc, mix = run.resolve(SPEC, cell)
-    doc, mix = shrink(doc, mix)
+    doc, mix = shrink(c, doc, mix)
     out = run.run_cell(SPEC, c, doc, mix, seed=2 ** 31 + 7, seconds=1.0,
                        trace=bool(trace))
     assert out["correct"], out

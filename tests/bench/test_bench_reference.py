@@ -1,5 +1,7 @@
 """The float32 reference of ``bench/reference/dense.py`` against the
-program's model (``repro.models``) at a smoke size, partial RoPE on."""
+program's model (``repro.models``) at a smoke size, partial RoPE on, with
+config and weights from ``bench/models/dense.py`` as the serve driver takes
+them."""
 import json
 from pathlib import Path
 
@@ -8,19 +10,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.drivers import serve
+from bench.models import dense as model
 from bench.reference import dense
-from smoke import shrink
+from smoke import at_smoke
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2 ** 31 + 11
 
 
 def _smoke():
-    doc = json.loads((ROOT / "bench/configs/phi4-mini-3.8b.json").read_text())
-    doc, _ = shrink(doc, {"arrivals": {}, "prompt": {}, "output": {}})
-    cfg, _ = serve.model_config(doc)
-    return doc, cfg, dense.Shapes.of(doc)
+    path = "bench/configs/phi4-mini-3.8b.json"
+    doc = at_smoke(json.loads((ROOT / path).read_text()), path)
+    cfg, _ = model.program_config(doc)
+    return doc, cfg, model.shapes(doc)
 
 
 def test_config_file_sets_the_published_partial_rope():
@@ -29,7 +31,7 @@ def test_config_file_sets_the_published_partial_rope():
     assert sh.rope_dims == 12            # 0.75 of a head of 16
     full = json.loads((ROOT / "bench/configs/phi4-mini-3.8b.json")
                       .read_text())
-    fcfg, changed = serve.model_config(full)
+    fcfg, changed = model.program_config(full)
     assert (fcfg.n_layers, fcfg.d_model, fcfg.n_heads, fcfg.n_kv_heads,
             fcfg.resolved_head_dim, fcfg.d_ff, fcfg.vocab_size) == \
         (32, 3072, 24, 8, 128, 8192, 200064)
@@ -72,8 +74,8 @@ def _served(cfg, sh, params, n_req=3):
 
 def test_served_tokens_sit_at_the_reference_top():
     _, cfg, sh = _smoke()
-    seqs = _served(cfg, sh, serve.program_params(cfg, sh, SEED))
-    gaps = dense.token_gaps(sh, SEED, seqs, control=True)
+    seqs = _served(cfg, sh, model.program_params(cfg, sh, SEED))
+    gaps = model.token_gaps(sh, SEED, seqs, control=True)
     worst = max(float(g["gap"].max()) for g in gaps)
     control = max(float(g["control_gap"].max()) for g in gaps)
     assert worst < 0.02
@@ -84,7 +86,7 @@ def test_served_tokens_sit_at_the_reference_top():
 def test_without_the_rope_permutation_the_reference_disagrees(monkeypatch):
     _, cfg, sh = _smoke()
     monkeypatch.setattr(dense, "interleave_rope", lambda w, r: w)
-    seqs = _served(cfg, sh, serve.program_params(cfg, sh, SEED))
+    seqs = _served(cfg, sh, model.program_params(cfg, sh, SEED))
     monkeypatch.undo()
-    gaps = dense.token_gaps(sh, SEED, seqs)
+    gaps = model.token_gaps(sh, SEED, seqs)
     assert max(float(g["gap"].max()) for g in gaps) > 0.05
