@@ -45,6 +45,9 @@ class SSMConfig:
     d_conv: int = 4
     expand: int = 2
     dt_rank: int = 0  # 0 -> ceil(d_model/16)
+    # RMSNorm on dt (width dt_rank), B and C (width d_state) between the
+    # x-projection and dt_proj, as every Jamba's mixer has them
+    inner_norms: bool = False
 
 
 @dataclass(frozen=True)
@@ -158,6 +161,8 @@ class ModelConfig:
                 dtr = s.dt_rank or -(-d // 16)
                 blk = (d * 2 * d_in + d_in * s.d_conv + d_in * (dtr + 2 * s.d_state)
                        + dtr * d_in + d_in * s.d_state + d_in + d_in * d)
+                if s.inner_norms:
+                    blk += dtr + 2 * s.d_state
             elif kind == "mlstm":
                 d_in = int(self.xlstm.proj_factor_mlstm * d)
                 blk = 2 * d * d_in + 3 * d_in * d_in // max(self.n_heads, 1) + d_in * d
@@ -244,6 +249,7 @@ class RunConfig:
 
 ARCH_IDS = (
     "jamba_v01_52b",
+    "jamba2_3b",
     "arctic_480b",
     "granite_moe_1b_a400m",
     "phi4_mini_3_8b",
@@ -257,6 +263,7 @@ ARCH_IDS = (
 
 _ALIASES = {
     "jamba-v0.1-52b": "jamba_v01_52b",
+    "jamba2-3b": "jamba2_3b",
     "arctic-480b": "arctic_480b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "phi4-mini-3.8b": "phi4_mini_3_8b",

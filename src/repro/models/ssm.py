@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
-from repro.models.layers import dtype_of
+from repro.models.layers import dtype_of, rmsnorm, rmsnorm_init
 
 SSM_CHUNK = 64
 
@@ -52,6 +52,8 @@ def ssm_init(key, cfg: ModelConfig):
         "a_log": jnp.log(jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32), (din, 1))),
         "ssm_d": jnp.ones((din,), jnp.float32),
         "out_proj": (jax.random.normal(ks[7], (din, d)) * din ** -0.5).astype(dt),
+        **({"dt_norm": rmsnorm_init(dtr), "b_norm": rmsnorm_init(n),
+            "c_norm": rmsnorm_init(n)} if s.inner_norms else {}),
     }
 
 
@@ -89,7 +91,11 @@ def _chunk_scan(dA, dBx, h0):
 def selective_scan(u, dt, A, B, C, D, h0=None, chunk: int = SSM_CHUNK):
     """u: [B,S,din]; dt: [B,S,din]; A: [din,N]; B,C: [B,S,N]; D: [din].
 
-    Returns (y [B,S,din], h_last [B,din,N]). All math fp32.
+    Returns (y [B,S,din], h_last [B,din,N]), both fp32: all math fp32, and
+    the caller rounds y once, after its gate. A length that is not a
+    multiple of the chunk is padded at its end with dt = 0 and u = 0: such
+    a step leaves h as it is (exp(0 * A) = 1, dt * u * B = 0), and its
+    outputs are dropped.
     """
     Bb, S, din = u.shape
     N = A.shape[1]
@@ -98,8 +104,11 @@ def selective_scan(u, dt, A, B, C, D, h0=None, chunk: int = SSM_CHUNK):
     if h0 is None:
         h0 = jnp.zeros((Bb, din, N), jnp.float32)
     chunk = min(chunk, S)
-    assert S % chunk == 0, (S, chunk)
-    nchunks = S // chunk
+    nchunks = -(-S // chunk)
+    pad = nchunks * chunk - S
+    if pad:
+        u32, dt32, B32, C32 = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                               for a in (u32, dt32, B32, C32))
 
     def step(h, xs):
         uc, dtc, Bc, Cc = xs  # [B, chunk, ...]
@@ -114,9 +123,9 @@ def selective_scan(u, dt, A, B, C, D, h0=None, chunk: int = SSM_CHUNK):
         for a in (u32, dt32, B32, C32)
     )
     h_last, ys = jax.lax.scan(step, h0, xs)
-    y = ys.swapaxes(0, 1).reshape(Bb, S, din)
-    y = y + u32 * D
-    return y.astype(u.dtype), h_last
+    y = ys.swapaxes(0, 1).reshape(Bb, nchunks * chunk, din)[:, :S]
+    y = y + u32[:, :S] * D
+    return y, h_last
 
 
 def ssm_apply(params, x, cfg: ModelConfig,
@@ -132,21 +141,33 @@ def ssm_apply(params, x, cfg: ModelConfig,
     xz = constrain(xz, "batch", "seq", "ssm_inner")
     xi, z = jnp.split(xz, 2, axis=-1)
     conv_state = state["conv"] if state is not None else None
-    xi, new_conv = causal_conv1d(xi, params["conv_w"], params["conv_b"], conv_state)
-    xi = jax.nn.silu(xi)
+    f32 = jnp.float32
+    # the taps, the activation and the gate in float32, rounded once before
+    # each projection, as the fused Mamba kernels compute them
+    xi, new_conv = causal_conv1d(
+        xi.astype(f32), params["conv_w"].astype(f32),
+        params["conv_b"].astype(f32),
+        None if conv_state is None else conv_state.astype(f32))
+    xi = jax.nn.silu(xi).astype(x.dtype)
 
     dt_in = xi @ params["x_dt"]
-    dt = jax.nn.softplus(dt_in @ params["dt_proj"] + params["dt_bias"])
     Bm = xi @ params["x_b"]
     Cm = xi @ params["x_c"]
+    if cfg.ssm.inner_norms:
+        dt_in = rmsnorm(params["dt_norm"], dt_in, cfg.norm_eps)
+        Bm = rmsnorm(params["b_norm"], Bm, cfg.norm_eps)
+        Cm = rmsnorm(params["c_norm"], Cm, cfg.norm_eps)
+    dt = jax.nn.softplus(dt_in @ params["dt_proj"] + params["dt_bias"])
     A = -jnp.exp(params["a_log"])
     h0 = state["h"] if state is not None else None
     if use_pallas and S > 1:
         from repro.kernels.ops import ssm_scan as pallas_scan
-        y, h_last = pallas_scan(xi, dt, A, Bm, Cm, params["ssm_d"], h0=h0)
+        # u in fp32, so that the kernel writes y in fp32 too
+        y, h_last = pallas_scan(xi.astype(f32), dt, A, Bm, Cm,
+                                params["ssm_d"], h0=h0)
     else:
         y, h_last = selective_scan(xi, dt, A, Bm, Cm, params["ssm_d"], h0=h0)
-    y = y * jax.nn.silu(z)
+    y = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype)
     out = y @ params["out_proj"]
     out = constrain(out, "batch", "seq", "embed")
     new_state = None

@@ -157,6 +157,11 @@ def stack_apply(params, x, positions, cfg: ModelConfig,
 # Cache construction
 # ---------------------------------------------------------------------------
 
+#: the axis of the batch in every leaf of ``init_caches``: each layer's
+#: cache puts the batch first, and the group axis is stacked before it
+BATCH_AXIS = 1
+
+
 def _one_cache(cfg: ModelConfig, layer_pos: int, batch: int, max_len: int,
                spec: bool):
     kind = cfg.layer_kind(layer_pos)
@@ -169,6 +174,18 @@ def _one_cache(cfg: ModelConfig, layer_pos: int, batch: int, max_len: int,
             return xlstm_lib.xlstm_state_spec(cfg, batch, kind)
         return xlstm_lib.init_xlstm_state(cfg, batch, kind)
     raise ValueError(kind)
+
+
+def cache_layout(cfg: ModelConfig):
+    """The kind of each leaf of ``init_caches(cfg, ...)``, in the same
+    tree: "kv" (keys and values by position) for an attention layer's
+    cache, "state" (recurrent) for any other layer's."""
+    out = {}
+    for p in range(cfg.resolved_scan_period):
+        kind = "kv" if cfg.layer_kind(p) == "attn" else "state"
+        out[_pos_name(p)] = jax.tree_util.tree_map(
+            lambda _: kind, _one_cache(cfg, p, 1, 1, spec=True))
+    return out
 
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int, spec: bool = False):

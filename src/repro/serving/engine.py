@@ -29,10 +29,17 @@ from repro.configs.base import ModelConfig
 from repro.core.job import Job, ResourceRequest, Task
 from repro.core.resources import ResourceManager
 from repro.models import build_model
-from repro.models.transformer import init_caches
+from repro.models.transformer import BATCH_AXIS, cache_layout, init_caches
 from repro.obs.spans import clock, mark, span
 
 _req_ids = itertools.count(1)
+
+
+def _write_lane(dst, src, lane):
+    """Each 1-lane leaf of ``src`` into lane ``lane`` of its ``dst`` leaf."""
+    return [jax.lax.dynamic_update_index_in_dim(d, x.astype(d.dtype), lane,
+                                                BATCH_AXIS)
+            for d, x in zip(dst, src)]
 
 
 @dataclass
@@ -65,6 +72,7 @@ class ServingEngine:
         self.greedy = greedy
         # lane state
         self.caches = init_caches(cfg, lanes, max_len)
+        self.cache_layout = cache_layout(cfg)
         self.positions = np.zeros((lanes,), np.int32)   # next write index
         self.lane_req: List[Optional[ServeRequest]] = [None] * lanes
         self.active_mask = np.zeros((lanes,), bool)
@@ -76,6 +84,8 @@ class ServingEngine:
         self._decode = jax.jit(
             self._decode_fn, donate_argnums=(1,) if donate else ())
         self._prefill_one = jax.jit(self._prefill_fn)
+        self._write_lane = jax.jit(
+            _write_lane, donate_argnums=(0,) if donate else ())
         self.steps = 0
         self.decode_tokens = 0
 
@@ -124,7 +134,7 @@ class ServingEngine:
             prompt = jnp.asarray(req.prompt, jnp.int32)[None]
             next_tok, new_caches = self._prefill_one(self.params, prompt)
         with span("engine.scatter", rid):
-            self._scatter_lane(lane, new_caches)
+            self._scatter_lane(lane, new_caches, rid)
         with span("engine.first_token", rid):
             tok = int(next_tok[0])
         req.output.append(tok)
@@ -141,13 +151,41 @@ class ServingEngine:
         self.lane_req[lane] = req
         self.active_mask[lane] = True
 
-    def _scatter_lane(self, lane: int, src_caches) -> None:
-        """Copy a 1-lane cache pytree into lane `lane` of the engine cache."""
-        def scat(dst, src):
-            if dst.ndim == src.ndim and dst.shape[1] == self.lanes:
-                return dst.at[:, lane].set(src[:, 0].astype(dst.dtype))
-            return dst
-        self.caches = jax.tree_util.tree_map(scat, self.caches, src_caches)
+    def _scatter_lane(self, lane: int, src_caches, key=None) -> None:
+        """Copy a 1-lane cache pytree into lane `lane` of the engine cache.
+
+        Every leaf is written along ``BATCH_AXIS``, by the kind that
+        ``cache_layout`` declares for it: the keys and values in one jitted
+        call inside span ``engine.scatter.kv``, the recurrent state in
+        another inside ``engine.scatter.state`` (keyed by ``key``), the
+        engine's caches donated to each. A leaf with no declared kind, or
+        whose shape does not fit the lane axis, raises: a leaf left as it
+        was would hand one request's state to the next in the lane."""
+        dst, tree = jax.tree_util.tree_flatten(self.caches)
+        src = tree.flatten_up_to(src_caches)
+        try:
+            kinds = tree.flatten_up_to(self.cache_layout)
+        except (TypeError, ValueError) as e:
+            raise ValueError(f"a cache leaf has no declared kind: {e}") from e
+        if not set(kinds) <= {"kv", "state"}:
+            raise ValueError(f"a cache leaf has no declared kind: {kinds}")
+        rest = lambda a: a.shape[:BATCH_AXIS] + a.shape[BATCH_AXIS + 1:]
+        for d, x in zip(dst, src):
+            if (d.ndim != x.ndim or d.shape[BATCH_AXIS] != self.lanes
+                    or x.shape[BATCH_AXIS] != 1 or rest(d) != rest(x)):
+                raise ValueError(f"a cache leaf of shape {x.shape} does not "
+                                 f"fit lane axis {BATCH_AXIS} of {d.shape}")
+        out = list(dst)
+        for kind in ("kv", "state"):
+            idx = [i for i, k in enumerate(kinds) if k == kind]
+            if not idx:
+                continue
+            with span(f"engine.scatter.{kind}", key):
+                new = self._write_lane([dst[i] for i in idx],
+                                       [src[i] for i in idx], lane)
+            for i, a in zip(idx, new):
+                out[i] = a
+        self.caches = tree.unflatten(out)
 
     # ------------------------------------------------------------- step
     def step(self) -> int:

@@ -17,6 +17,7 @@ from repro.models import build_model
 
 CONSISTENCY_ARCHS = ["phi4_mini_3_8b", "gemma_2b", "chatglm3_6b",
                      "codeqwen15_7b", "musicgen_large", "jamba_v01_52b",
+                     "jamba2_3b",
                      "xlstm_1_3b", "granite_moe_1b_a400m"]
 
 

@@ -77,6 +77,7 @@ def test_full_config_matches_assignment(arch):
     cfg = get_config(arch)
     expected = {
         "jamba_v01_52b": (32, 4096, 32, 8, 14336, 65536),
+        "jamba2_3b": (28, 2560, 20, 1, 8192, 65536),
         "arctic_480b": (35, 7168, 56, 8, 4864, 32000),
         "granite_moe_1b_a400m": (24, 1024, 16, 8, 512, 49155),
         "phi4_mini_3_8b": (32, 3072, 24, 8, 8192, 200064),

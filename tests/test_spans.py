@@ -229,3 +229,36 @@ def test_engine_spans_for_each_request_and_step(tmp_path):
     for part in ("engine.prepare", "engine.sync", "engine.retire"):
         assert [r[1] for r in cap.of(part)] == n
     assert 0 < stats["mean_latency_s"] < stats["wall_s"]
+
+
+@pytest.mark.parametrize("arch", ["phi4_mini_3_8b", "jamba2_3b"])
+def test_engine_lane_writes_by_cache_kind(tmp_path, arch):
+    """``engine.scatter.kv`` and, in a stack with recurrent layers,
+    ``engine.scatter.state``, once per admission, inside its
+    ``engine.scatter`` and keyed by the request's id."""
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    from repro.serving import ServeRequest, ServingEngine
+
+    cfg = get_smoke_config(arch)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, lanes=2, max_len=32)
+    rng = np.random.default_rng(7)
+
+    def reqs(k):
+        return [ServeRequest(prompt=list(rng.integers(0, cfg.vocab_size, 5)),
+                             max_new_tokens=2) for _ in range(k)]
+    eng.run(reqs(2))                              # compiles
+    rs = reqs(3)
+    with capture(tmp_path):
+        eng.run(rs)
+    cap = spans.last_capture()
+    scatter = {k: (t0, t1) for _, k, t0, t1 in cap.of("engine.scatter")}
+    assert sorted(scatter) == [r.request_id for r in rs]
+    kinds = ["kv", "state"] if cfg.family == "hybrid" else ["kv"]
+    for kind in ("kv", "state"):
+        got = {k: (t0, t1) for _, k, t0, t1
+               in cap.of(f"engine.scatter.{kind}")}
+        assert sorted(got) == (sorted(scatter) if kind in kinds else [])
+        for k, (t0, t1) in got.items():
+            assert scatter[k][0] <= t0 <= t1 <= scatter[k][1]
